@@ -861,19 +861,20 @@ def _group_tokens(h: jax.Array, g: jax.Array, expert_idx: jax.Array,
     """Grouped-dispatch pre-pass shared by the XLA and Pallas serve paths.
 
     Scatters tokens (UNscaled) and their fp32 gate values into per-expert
-    capacity buffers. Returns (buf (K,C,d), g_buf (K,C), slot, valid)."""
+    capacity buffers. Returns (buf (K,C,d), g_buf (K,C), slot, valid).
+    Tokens that are not dispatched (overflow, or an out-of-range expert id)
+    land in one spare row that is sliced off: no scatter index is ever out
+    of bounds."""
     from repro.core.dispatch import dispatch_indices
 
     d = h.shape[-1]
     slot, valid = dispatch_indices(expert_idx, K, capacity)
-    s_k = jnp.where(valid, slot, capacity)
-    buf = jnp.zeros((K, capacity, d), h.dtype)
-    buf = buf.at[expert_idx, s_k].set(h, mode="drop")
-    g_buf = jnp.zeros((K, capacity), jnp.float32)
-    g_buf = g_buf.at[expert_idx, s_k].set(
-        jnp.where(valid, g.astype(jnp.float32), 0.0), mode="drop"
-    )
-    return buf, g_buf, slot, valid
+    flat = jnp.where(valid, expert_idx * capacity + slot, K * capacity)
+    buf = jnp.zeros((K * capacity + 1, d), h.dtype).at[flat].set(h)
+    g_buf = jnp.zeros((K * capacity + 1,), jnp.float32).at[flat].set(
+        g.astype(jnp.float32))
+    return (buf[:-1].reshape(K, capacity, d),
+            g_buf[:-1].reshape(K, capacity), slot, valid)
 
 
 def _overflow_fixup(table, h, g, expert_idx, valid, vals, ids, k,
@@ -892,7 +893,8 @@ def _overflow_fixup(table, h, g, expert_idx, valid, vals, ids, k,
     B = h.shape[0]
     K = table.ids.shape[0]
     O = min(B, max(capacity, K))
-    # All overflow positions, padded with the out-of-range sentinel B.
+    # All overflow positions, padded with the sentinel B: row B of the
+    # outputs is a spare row, sliced off after the loop.
     over_all = jnp.nonzero(~valid, size=B, fill_value=B)[0]  # (B,)
     n_over = jnp.sum((~valid).astype(jnp.int32))
     n_chunks = (n_over + O - 1) // O  # dynamic — lowers to a while loop
@@ -907,15 +909,18 @@ def _overflow_fixup(table, h, g, expert_idx, valid, vals, ids, k,
         z_o = jnp.where(ids_o >= 0, z_o, NEG_INF)
         v_o, p_o = jax.lax.top_k(z_o, k)
         i_o = jnp.take_along_axis(ids_o, p_o, axis=1)
-        # Scatter through the UNclamped index with mode='drop': sentinel rows
-        # (idx == B) fall out of bounds and are discarded — clamping them to
-        # B-1 would duplicate that index and could clobber a real fixup of
-        # the last token with its stale pre-update value.
-        vals = vals.at[idx].set(v_o, mode="drop")
-        ids = ids.at[idx].set(i_o, mode="drop")
+        # Scatter through the UNclamped index: sentinel rows (idx == B) land
+        # in the spare row — clamping them to B-1 would duplicate that index
+        # and could clobber a real fixup of the last token with its stale
+        # pre-update value.
+        vals = vals.at[idx].set(v_o)
+        ids = ids.at[idx].set(i_o)
         return vals, ids
 
-    return jax.lax.fori_loop(0, n_chunks, chunk, (vals, ids))
+    spare = ((0, 1), (0, 0))
+    vals, ids = jax.lax.fori_loop(
+        0, n_chunks, chunk, (jnp.pad(vals, spare), jnp.pad(ids, spare)))
+    return vals[:B], ids[:B]
 
 
 def _serve_topk_grouped(
@@ -935,12 +940,13 @@ def _serve_topk_grouped(
     (rare with the load-balance loss; exactness preserved).
 
     ``owned`` (sharded serving): non-owned tokens are routed to the
-    out-of-range expert id K before dispatch, so the ``mode='drop'``
-    scatters keep them out of every capacity buffer, and they are masked
-    valid for the fixup (a non-owned token must never trigger the gather
-    fallback on this shard). ``n_experts_global`` sizes ``capacity`` by
-    the global expert count: the shard sees ~B/ep of the tokens spread
-    over K/ep experts — the same per-expert load as the global run.
+    out-of-range expert id K before dispatch, which ``dispatch_indices``
+    never marks valid, so they stay out of every capacity buffer; they
+    are then masked valid for the fixup (a non-owned token must never
+    trigger the gather fallback on this shard). ``n_experts_global``
+    sizes ``capacity`` by the global expert count: the shard sees ~B/ep
+    of the tokens spread over K/ep experts — the same per-expert load as
+    the global run.
 
     Returns (vals, ids, overflow) with ``overflow`` the (K,) int32
     per-expert count of owned tokens that paid the fixup this call.
@@ -1157,7 +1163,6 @@ def serve_topk_sharded(
     it owns (summed over the data axes), and the shards' (K_loc,) rows
     concatenate over ``model`` — O(K) extra wire, never O(B·V_pad).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.dispatch import dispatch_load
@@ -1247,11 +1252,11 @@ def serve_topk_sharded(
 
     out = P(bspec, None)
     stat = P("model")  # shards own disjoint K_loc expert rows → concat
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), _table_pspecs(table), P(bspec, None)),
         out_specs=(out, out) + ((stat, stat) if with_stats else ()),
-        check_rep=False,
+        check_vma=False,
     )
     res = fn(gate_w, table, h)
     if not with_stats:

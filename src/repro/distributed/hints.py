@@ -1,31 +1,28 @@
 """In-model sharding hints that degrade to no-ops off-mesh.
 
 Model code calls ``constrain(x, None, "model", ...)`` to pin an
-intermediate's layout; outside a mesh context (CPU smoke tests) the call is
-a no-op, and axes absent from the active mesh are dropped.
+intermediate's layout. The active mesh is the one ``jax.set_mesh`` set;
+outside one (single-device serving, CPU smoke tests) the call is a no-op.
+Axes absent from the mesh, and axes a ``shard_map`` body has already
+split (``Manual``), are dropped. A constraint that still fails raises.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 
 def _active_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:  # legacy `with mesh:` context
-        from jax._src import mesh as mesh_lib
+    """The abstract mesh set by ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
-        env = mesh_lib.thread_resources.env
-        if env.physical_mesh and not env.physical_mesh.empty:
-            return env.physical_mesh
-    except Exception:
-        pass
-    return None
+
+def _auto_axes(mesh) -> set:
+    """Mesh axes a sharding constraint may name: inside a ``shard_map``
+    body the mapped axes are ``Manual`` and already split."""
+    return {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t != AxisType.Manual}
 
 
 REP = "rep"  # sentinel: force this dim replicated (None = leave unconstrained)
@@ -63,9 +60,9 @@ def constrain(x, *spec):
     sentinel forces replication of a dim.
     """
     mesh = _active_mesh()
-    if mesh is None:
+    names = _auto_axes(mesh) if mesh is not None else set()
+    if not names:
         return x
-    names = set(mesh.axis_names)
     U = P.UNCONSTRAINED
 
     def ok(axis):
@@ -81,7 +78,4 @@ def constrain(x, *spec):
 
     spec2 = tuple(ok(a) for a in spec)
     spec2 = spec2[: x.ndim] + (U,) * max(0, x.ndim - len(spec2))
-    try:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec2)))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec2)))

@@ -162,12 +162,9 @@ def constrain_like_params(tree: Any) -> Any:
         return tree
 
     def leaf(path, x):
-        try:
-            return jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, param_pspec(path, x.ndim))
-            )
-        except Exception:
-            return x
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, _fitted_pspec(path, tuple(x.shape), mesh))
+        )
 
     return map_with_path(leaf, tree)
 
@@ -413,8 +410,6 @@ class ServeParamGather:
         return specs
 
     def _gather(self, prefix: str, tree: Any, drop_leading: bool) -> Any:
-        from jax.experimental.shard_map import shard_map
-
         specs = self._specs_for(prefix, tree, drop_leading)
         if all(all(ax is None for ax in s) for s in specs):
             return tree  # fully replicated (trivial data axis / small leaves)
@@ -431,11 +426,11 @@ class ServeParamGather:
                 out.append(x)
             return tuple(out)
 
-        out = shard_map(
+        out = jax.shard_map(
             inner, mesh=self.mesh,
             in_specs=tuple(specs),
             out_specs=tuple(P(*([None] * len(s))) for s in specs),
-            check_rep=False,
+            check_vma=False,
         )(*flat)
         return jax.tree_util.tree_unflatten(treedef, out)
 
@@ -454,8 +449,6 @@ class ServeParamGather:
     def rows(self, key: str, table: jax.Array, ids: jax.Array) -> jax.Array:
         """``table[ids]`` from a ``(N, d)`` table stored d-sharded: local
         take + one O(ids·d) all-gather of the *activation* rows."""
-        from jax.experimental.shard_map import shard_map
-
         path = key if key in self._spec else f"{key}/table"
         spec = self._spec[path]
         d_ax = tuple(spec)[-1]
@@ -470,11 +463,11 @@ class ServeParamGather:
             rows = jnp.take(tbl, tok, axis=0)
             return jax.lax.all_gather(rows, d_ax, axis=-1, tiled=True)
 
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=self.mesh,
             in_specs=(spec, P(*([None] * ids.ndim))),
             out_specs=P(*([None] * (ids.ndim + 1))),
-            check_rep=False,
+            check_vma=False,
         )(table, ids)
 
 

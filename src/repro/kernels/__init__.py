@@ -1,8 +1,10 @@
 """Pallas TPU kernels for the paper's compute hot-spots.
 
-Each kernel ships with a pure-jnp oracle in ``ref.py``; tests sweep
-shapes/dtypes in ``interpret=True`` mode (this container is CPU-only — TPU
-is the compile target, the interpreter validates semantics).
+Each kernel ships with a pure-jnp oracle in ``ref.py``. Off TPU the
+kernels run in Pallas interpret mode, where the tests sweep shapes and
+dtypes against the oracles; interpret mode checks semantics only.
+``tests/test_tpu_compile.py`` compiles the DS-head kernels for a
+described TPU v5e chip, and ``chip_smoke.py`` runs them on one.
 """
 from repro.kernels import ops, ref, registry
 from repro.kernels.dss_topk import dss_topk

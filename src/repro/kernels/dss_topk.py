@@ -70,29 +70,39 @@ def _block_v(v_pad: int, d: int, dtype_bytes: int = 2, budget: int = 4 * 2 ** 20
     return 128
 
 
-def _kernel(eidx_ref, w_ref, ids_ref, h_ref, vals_ref, idx_ref, *, k: int, block_v: int):
+def _kernel(eidx_ref, w_ref, ids_ref, h_ref, g_ref, vals_ref, idx_ref, *,
+            k: int):
     del eidx_ref  # consumed by the index maps
-    w = w_ref[0]  # (block_v, d)
-    h = h_ref[...]  # (1, d)
-    ids = ids_ref[...]  # (1, block_v)
-    z = jnp.dot(w, h.T, preferred_element_type=jnp.float32)  # (block_v, 1)
-    z = jnp.where(ids.T >= 0, z, NEG_INF)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (block_v, 1), 0)
-    # unrolled top-k within the block (k is small and static)
-    for i in range(k):
-        m = jnp.max(z)
-        am = jnp.argmax(z[:, 0])
-        vals_ref[0, 0, i] = m
-        idx_ref[0, 0, i] = ids[0, am]
-        z = jnp.where(iota == am, NEG_INF, z)
+    w = w_ref[0]        # (block_v, d)
+    h = h_ref[0]        # (1, d)
+    ids = ids_ref[0]    # (1, block_v)
+    z = jax.lax.dot_general(
+        h, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # (1, block_v)
+    z = z * g_ref[0]    # gate scale AFTER the matmul, like the oracle
+    z = jnp.where(ids >= 0, z, NEG_INF)
+    col = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
+    # unrolled top-k within the block (k is small and static); the first
+    # maximal column wins ties, like jax.lax.top_k
+    vals, idxs = [], []
+    for _ in range(k):
+        m = jnp.max(z, axis=1, keepdims=True)
+        am = jnp.min(jnp.where(z == m, col, z.shape[1]), axis=1, keepdims=True)
+        hit = col == am
+        vals.append(m[:, 0])
+        idxs.append(jnp.sum(jnp.where(hit, ids, 0), axis=1))
+        z = jnp.where(hit, -jnp.inf, z)
+    vals_ref[0, 0] = jnp.stack(vals, axis=1)
+    idx_ref[0, 0] = jnp.stack(idxs, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret", "block_v"))
 def dss_topk(
     weights: jax.Array,   # (K, V_pad, d)
     ids: jax.Array,       # (K, V_pad) int32, -1 = padding
-    h_scaled: jax.Array,  # (B, d) — pre-scaled by the gate value g
+    h: jax.Array,         # (B, d) tokens, unscaled
     expert_idx: jax.Array,  # (B,) int32
+    g: jax.Array,         # (B,) fp32 gate value per token
     k: int = 8,
     *,
     interpret: bool | None = None,
@@ -101,33 +111,36 @@ def dss_topk(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     K, v_pad, d = weights.shape
-    B = h_scaled.shape[0]
+    B = h.shape[0]
     bv = block_v or _block_v(v_pad, d, weights.dtype.itemsize)
     n_blocks = v_pad // bv
     grid = (B, n_blocks)
 
-    kern = functools.partial(_kernel, k=k, block_v=bv)
+    # Per-expert and per-token rows ride a unit middle axis so every block's
+    # last two dims are whole-array dims or (8, 128)-aligned (Mosaic tiling).
     vals, idxs = pl.pallas_call(
-        kern,
+        functools.partial(_kernel, k=k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, bv, d), lambda b, j, eidx: (eidx[b], j, 0)),
-                pl.BlockSpec((1, bv), lambda b, j, eidx: (eidx[b], j)),
-                pl.BlockSpec((1, d), lambda b, j, eidx: (b, 0)),
+                pl.BlockSpec((1, 1, bv), lambda b, j, eidx: (eidx[b], 0, j)),
+                pl.BlockSpec((1, 1, d), lambda b, j, eidx: (b, 0, 0)),
+                pl.BlockSpec((1, 1, 1), lambda b, j, eidx: (b, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, k), lambda b, j, eidx: (b, j, 0)),
-                pl.BlockSpec((1, 1, k), lambda b, j, eidx: (b, j, 0)),
+                pl.BlockSpec((1, 1, 1, k), lambda b, j, eidx: (b, j, 0, 0)),
+                pl.BlockSpec((1, 1, 1, k), lambda b, j, eidx: (b, j, 0, 0)),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, n_blocks, k), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_blocks, k), jnp.int32),
+            jax.ShapeDtypeStruct((B, n_blocks, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_blocks, 1, k), jnp.int32),
         ],
         interpret=interpret,
-    )(expert_idx, weights, ids, h_scaled)
+    )(expert_idx, weights, ids[:, None, :], h[:, None, :],
+      g.astype(jnp.float32)[:, None, None])
 
     # exact global top-k from the per-block candidates
     cand_v = vals.reshape(B, n_blocks * k)

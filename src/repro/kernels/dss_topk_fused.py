@@ -46,7 +46,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.dss_topk_grouped import (
     NEG_INF,
     _carry_width,
@@ -84,7 +83,7 @@ def _body(ebase_ref, gate_ref, h_ref, w_ref, ids_ref, s_ref,
 
     x = h_ref[...]            # (block_b, d) tokens, unscaled
     w = w_ref[0]              # (block_v, d) this expert's packed rows
-    row_ids = ids_ref[...]    # (1, block_v); -1 = padding
+    row_ids = ids_ref[0]      # (1, block_v); -1 = padding
 
     if s_ref is not None:
         w = w.astype(x.dtype)  # int8 rows → token dtype for the MXU
@@ -92,7 +91,7 @@ def _body(ebase_ref, gate_ref, h_ref, w_ref, ids_ref, s_ref,
         x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (block_b, block_v)
     if s_ref is not None:
-        z = z * s_ref[...][0][None, :]     # per-row dequant scale
+        z = z * s_ref[0]                   # per-row dequant scale
     z = z * gs_ref[...]                    # gate scale AFTER the matmul
     z = jnp.where(row_ids >= 0, z, NEG_INF)
     # Not-my-expert rows drop to -inf — strictly below the selected
@@ -180,17 +179,20 @@ def dss_topk_fused(
     else:
         e_base = jnp.reshape(jnp.asarray(e_base, jnp.int32), (1,))
 
+    # Per-row ids and scales ride as (K, 1, V) rows so every block's last
+    # two dims are whole-array dims or (8, 128)-aligned (Mosaic tiling).
     K_real = gate_w.shape[0]
     in_specs = [
         pl.BlockSpec((K_real, d), lambda t, e, jv, eb: (0, 0)),
         pl.BlockSpec((bb, d), lambda t, e, jv, eb: (t, 0)),
         pl.BlockSpec((1, bv, d), lambda t, e, jv, eb: (e, jv, 0)),
-        pl.BlockSpec((1, bv), lambda t, e, jv, eb: (e, jv)),
+        pl.BlockSpec((1, 1, bv), lambda t, e, jv, eb: (e, 0, jv)),
     ]
-    operands = [gate_w, h, weights, ids]
+    operands = [gate_w, h, weights, ids[:, None, :]]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, bv), lambda t, e, jv, eb: (e, jv)))
-        operands.append(scales.astype(jnp.float32))
+        in_specs.append(
+            pl.BlockSpec((1, 1, bv), lambda t, e, jv, eb: (e, 0, jv)))
+        operands.append(scales.astype(jnp.float32)[:, None, :])
 
     kern = functools.partial(_kernel_q if quantized else _kernel,
                              k=k, n_e=K, n_vb=n_vb)
@@ -217,7 +219,7 @@ def dss_topk_fused(
             jax.ShapeDtypeStruct((b_pad, k), jnp.int32),
             jax.ShapeDtypeStruct((b_pad, 1), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,

@@ -49,7 +49,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e9
 _LANES = 128
@@ -116,8 +115,8 @@ def _body(buf_ref, g_ref, w_ref, ids_ref, s_ref, vals_ref, idx_ref,
 
     x = buf_ref[0]            # (block_b, d) — grouped tokens, unscaled
     w = w_ref[0]              # (block_v, d) — this expert's packed rows
-    g = g_ref[...]            # (1, block_b) — fp32 gate values
-    row_ids = ids_ref[...]    # (1, block_v) — class id per row; -1 = padding
+    g = g_ref[0]              # (block_b, 1) — fp32 gate values
+    row_ids = ids_ref[0]      # (1, block_v) — class id per row; -1 = padding
 
     if s_ref is not None:
         w = w.astype(x.dtype)  # int8 rows → token dtype for the MXU
@@ -127,8 +126,8 @@ def _body(buf_ref, g_ref, w_ref, ids_ref, s_ref, vals_ref, idx_ref,
         x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (block_b, block_v)
     if s_ref is not None:
-        z = z * s_ref[...][0][None, :]           # per-row dequant scale
-    z = z * g[0][:, None]                        # gate scale AFTER the matmul
+        z = z * s_ref[0]                         # per-row dequant scale
+    z = z * g                                    # gate scale AFTER the matmul
     z = jnp.where(row_ids >= 0, z, NEG_INF)      # mask table padding
 
     _merge_topk_carry(z, row_ids, vs_ref, is_ref, k=k)
@@ -205,16 +204,19 @@ def dss_topk_grouped(
     n_vb = v_rounded // bv
     grid = (K, n_tb, n_vb)
 
+    # Per-slot gate values ride as a (K, C, 1) column and per-row ids and
+    # scales as (K, 1, V) rows, so every block's last two dims are
+    # whole-array dims or (8, 128)-aligned (Mosaic tiling).
     in_specs = [
         pl.BlockSpec((1, bb, d), lambda e, t, jv: (e, t, 0)),
-        pl.BlockSpec((1, bb), lambda e, t, jv: (e, t)),
+        pl.BlockSpec((1, bb, 1), lambda e, t, jv: (e, t, 0)),
         pl.BlockSpec((1, bv, d), lambda e, t, jv: (e, jv, 0)),
-        pl.BlockSpec((1, bv), lambda e, t, jv: (e, jv)),
+        pl.BlockSpec((1, 1, bv), lambda e, t, jv: (e, 0, jv)),
     ]
-    operands = [buf, g_buf, weights, ids]
+    operands = [buf, g_buf[..., None], weights, ids[:, None, :]]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, bv), lambda e, t, jv: (e, jv)))
-        operands.append(scales.astype(jnp.float32))
+        in_specs.append(pl.BlockSpec((1, 1, bv), lambda e, t, jv: (e, 0, jv)))
+        operands.append(scales.astype(jnp.float32)[:, None, :])
 
     kern = functools.partial(_kernel_q if quantized else _kernel,
                              k=k, n_vb=n_vb)
@@ -234,7 +236,7 @@ def dss_topk_grouped(
             pltpu.VMEM((bb, k_pad), jnp.float32),
             pltpu.VMEM((bb, k_pad), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

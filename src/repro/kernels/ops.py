@@ -9,9 +9,6 @@ name through that registry and only then dispatches into these wrappers.
 """
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-
 from repro.kernels.dss_topk import dss_topk as _dss_topk_kernel
 from repro.kernels.dss_topk_fused import dss_topk_fused as _dss_topk_fused_kernel
 from repro.kernels.dss_topk_grouped import dss_topk_grouped as _dss_topk_grouped_kernel
@@ -22,10 +19,9 @@ from repro.kernels.lasso_prune import lasso_prune
 
 def dss_topk(weights, ids, h, expert_idx, g, k: int = 8, **kw):
     """Serve-path fused top-k (per-token streaming kernel). Matches
-    core.dssoftmax.serve_topk semantics: the gate value is folded into h
-    (z = g·(W h) = W·(g h))."""
-    h_scaled = (h.astype(jnp.float32) * g[:, None]).astype(h.dtype)
-    return _dss_topk_kernel(weights, ids, h_scaled, expert_idx, k, **kw)
+    core.dssoftmax.serve_topk semantics: the fp32 gate value scales the
+    fp32 logits after the matmul (z = g·(W h))."""
+    return _dss_topk_kernel(weights, ids, h, expert_idx, g, k, **kw)
 
 
 def dss_topk_grouped(weights, ids, buf, g_buf, k: int = 8, *, scales=None, **kw):

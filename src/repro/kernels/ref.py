@@ -7,16 +7,17 @@ import jax.numpy as jnp
 NEG_INF = -1e9
 
 
-def dss_topk_ref(weights, ids, h_scaled, expert_idx, k):
+def dss_topk_ref(weights, ids, h, expert_idx, g, k):
     """Oracle for the fused DS-Softmax serve kernel.
 
-    weights: (K, V_pad, d); ids: (K, V_pad) int32 (-1 pad);
-    h_scaled: (B, d) — context pre-multiplied by the gate value;
-    expert_idx: (B,) int32. → (vals (B,k) f32, ids (B,k) int32).
+    weights: (K, V_pad, d); ids: (K, V_pad) int32 (-1 pad); h: (B, d);
+    expert_idx: (B,) int32; g: (B,) gate value, applied to the logits.
+    → (vals (B,k) f32, ids (B,k) int32).
     """
     w_sel = weights[expert_idx]  # (B, V_pad, d)
     ids_sel = ids[expert_idx]
-    z = jnp.einsum("bvd,bd->bv", w_sel.astype(jnp.float32), h_scaled.astype(jnp.float32))
+    z = jnp.einsum("bvd,bd->bv", w_sel.astype(jnp.float32), h.astype(jnp.float32))
+    z = z * g.astype(jnp.float32)[:, None]
     z = jnp.where(ids_sel >= 0, z, NEG_INF)
     vals, pos = jax.lax.top_k(z, k)
     return vals, jnp.take_along_axis(ids_sel, pos, axis=1)

@@ -32,8 +32,9 @@ crossover lands in the grouped regime at verify time. No pricing change
 is needed here: ``serve_kernel_context`` reads B from the head batch at
 trace time, so the verify step's context prices (and ``AutoPolicy``
 picks) the grouped paths automatically. Pallas paths are only feasible
-on TPU — elsewhere they lower through the interpreter (~25× slower than
-XLA), so :class:`AutoPolicy` never selects them off-TPU.
+on TPU, where they compile to Mosaic kernels; on other backends they run
+in interpret mode, which is for tests, so :class:`AutoPolicy` never
+selects them off-TPU.
 
 Sharded (expert-parallel) variants register as first-class ``*_ep``
 specs: their HBM model is the base path evaluated at the PER-DEVICE

@@ -73,7 +73,7 @@ def build_cell(arch: str, shape_name: str, head: str | None = None,
             cfg = cfg.replace(**{k: v})
     shape = SHAPES[shape_name]
     bundle = build(cfg)
-    mesh = None  # bound by caller via `with mesh:`
+    mesh = None  # bound by caller via `with jax.set_mesh(mesh):`
 
     params, ds_state = bundle.abstract_params()
     specs = input_specs(cfg, shape)
@@ -150,7 +150,7 @@ def run_cell(mesh, mesh_name: str, arch: str, shape_name: str, head=None, hlo_di
              overrides: dict | None = None, tag: str = ""):
     t0 = time.time()
     fn, args, cfg, shape = lower_cell(mesh, arch, shape_name, head, overrides=overrides)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

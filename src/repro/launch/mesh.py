@@ -17,11 +17,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / elastic reshapes)."""
-    try:  # jax >= 0.5 exposes AxisType; 0.4.x meshes are implicitly auto
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=axis_types)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=axis_types)
 
 
 def make_host_mesh():

@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduce_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build
 from repro.train import (
     AdaptPolicy,
@@ -132,6 +133,7 @@ def main():
     args = ap.parse_args()
     if args.param_mode == "fsdp" and not args.mesh:
         ap.error("--param-mode fsdp requires --mesh")
+    use_compile_cache()
 
     mesh = None
     if args.mesh:

@@ -19,8 +19,9 @@ def test_dss_topk_matches_ref(K, V, d, B, k, dtype):
     ).astype(jnp.int32)
     h = jax.random.normal(jax.random.PRNGKey(3), (B, d)).astype(dtype)
     eidx = jax.random.randint(jax.random.PRNGKey(4), (B,), 0, K)
-    v1, i1 = dss_topk(w, ids, h, eidx, k, interpret=True)
-    v2, i2 = ref.dss_topk_ref(w, ids, h, eidx, k)
+    g = jax.random.uniform(jax.random.PRNGKey(5), (B,), minval=0.1, maxval=1.0)
+    v1, i1 = dss_topk(w, ids, h, eidx, g, k, interpret=True)
+    v2, i2 = ref.dss_topk_ref(w, ids, h, eidx, g, k)
     np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=2e-2, atol=1e-4)
     assert np.array_equal(np.asarray(i1), np.asarray(i2))
 
