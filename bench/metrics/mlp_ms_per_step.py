@@ -1,0 +1,8 @@
+"""Model step: device time of the decode program's ops under the ``mlp``
+scope (ln2, the MLP, the residual add), ms per decode step. Nothing to
+read without the spans reduction (``spans.py``)."""
+from metrics.spans_common import scope_ms_per_step
+
+
+def read(r):
+    return scope_ms_per_step(r, "mlp")

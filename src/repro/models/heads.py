@@ -105,6 +105,7 @@ def head_loss(
     return ce, {"head_aux_total": jnp.zeros((), jnp.float32)}
 
 
+@jax.named_scope("ds_head")
 def head_topk(
     head_params,
     serve_table,

@@ -261,22 +261,25 @@ def attention_decode(params, cfg: ModelConfig, x, cache_k, cache_v, pos,
     if pages is not None:
         ps = cache_k.shape[1]
         rows = jnp.arange(B)
-        pid = pages[rows, pos_b // ps]  # (B,) write page per row
-        off = pos_b % ps
-        cache_k = cache_k.at[pid, off].set(k_new[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[pid, off].set(v_new[:, 0].astype(cache_v.dtype))
+        with jax.named_scope("kv_write"):
+            pid = pages[rows, pos_b // ps]  # (B,) write page per row
+            off = pos_b % ps
+            cache_k = cache_k.at[pid, off].set(k_new[:, 0].astype(cache_k.dtype))
+            cache_v = cache_v.at[pid, off].set(v_new[:, 0].astype(cache_v.dtype))
         n_pg = pages.shape[1]
         KVh, dh_ = cache_k.shape[2], cache_k.shape[3]
         view_k = cache_k[pages].reshape(B, n_pg * ps, KVh, dh_)
         view_v = cache_v[pages].reshape(B, n_pg * ps, KVh, dh_)
     elif per_row:
         rows = jnp.arange(B)
-        cache_k = cache_k.at[rows, pos_b].set(k_new[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[rows, pos_b].set(v_new[:, 0].astype(cache_v.dtype))
+        with jax.named_scope("kv_write"):
+            cache_k = cache_k.at[rows, pos_b].set(k_new[:, 0].astype(cache_k.dtype))
+            cache_v = cache_v.at[rows, pos_b].set(v_new[:, 0].astype(cache_v.dtype))
         view_k, view_v = cache_k, cache_v
     else:
-        cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k_new.astype(cache_k.dtype), pos, axis=1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v_new.astype(cache_v.dtype), pos, axis=1)
+        with jax.named_scope("kv_write"):
+            cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k_new.astype(cache_k.dtype), pos, axis=1)
+            cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v_new.astype(cache_v.dtype), pos, axis=1)
         view_k, view_v = cache_k, cache_v
     from repro.distributed.hints import BATCH, constrain
 
@@ -333,24 +336,27 @@ def attention_prefill_chunk(params, cfg: ModelConfig, x, cache_k, cache_v, pos0,
     q, k_new, v_new = _project_qkv(params, cfg, x, positions)
     if pages is not None:
         ps = cache_k.shape[1]
-        pid = jnp.take_along_axis(pages, positions // ps, axis=1)  # (B, C)
-        off = positions % ps
-        cache_k = cache_k.at[pid, off].set(k_new.astype(cache_k.dtype))
-        cache_v = cache_v.at[pid, off].set(v_new.astype(cache_v.dtype))
+        with jax.named_scope("kv_write"):
+            pid = jnp.take_along_axis(pages, positions // ps, axis=1)  # (B, C)
+            off = positions % ps
+            cache_k = cache_k.at[pid, off].set(k_new.astype(cache_k.dtype))
+            cache_v = cache_v.at[pid, off].set(v_new.astype(cache_v.dtype))
         n_pg = pages.shape[1]
         KVh, dh_ = cache_k.shape[2], cache_k.shape[3]
         view_k = cache_k[pages].reshape(B, n_pg * ps, KVh, dh_)
         view_v = cache_v[pages].reshape(B, n_pg * ps, KVh, dh_)
     elif per_row:
         rows = jnp.arange(B)[:, None]
-        cache_k = cache_k.at[rows, positions].set(k_new.astype(cache_k.dtype))
-        cache_v = cache_v.at[rows, positions].set(v_new.astype(cache_v.dtype))
+        with jax.named_scope("kv_write"):
+            cache_k = cache_k.at[rows, positions].set(k_new.astype(cache_k.dtype))
+            cache_v = cache_v.at[rows, positions].set(v_new.astype(cache_v.dtype))
         view_k, view_v = cache_k, cache_v
     else:
-        cache_k = jax.lax.dynamic_update_slice_in_dim(
-            cache_k, k_new.astype(cache_k.dtype), pos0, axis=1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(
-            cache_v, v_new.astype(cache_v.dtype), pos0, axis=1)
+        with jax.named_scope("kv_write"):
+            cache_k = jax.lax.dynamic_update_slice_in_dim(
+                cache_k, k_new.astype(cache_k.dtype), pos0, axis=1)
+            cache_v = jax.lax.dynamic_update_slice_in_dim(
+                cache_v, v_new.astype(cache_v.dtype), pos0, axis=1)
         view_k, view_v = cache_k, cache_v
     H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     G = H // KV

@@ -225,30 +225,39 @@ def _chunk_hidden(params, cfg: ModelConfig, cache: DecodeCache, tokens, pos0,
     run a (B, C) token block at positions ``pos0 .. pos0+C-1`` (scalar or
     per-row ``pos0``) against the cache. Returns (hidden (B, C, d), new
     cache_k, new cache_v)."""
-    if gather is not None:
-        x = gather.rows("embed/table", params["embed"]["table"], tokens)
-    else:
-        x = embed(params["embed"], tokens)  # (B, C, d)
+    with jax.named_scope("embed"):
+        if gather is not None:
+            x = gather.rows("embed/table", params["embed"]["table"], tokens)
+        else:
+            x = embed(params["embed"], tokens)  # (B, C, d)
 
     def body(carry, scanned):
         xc = carry
         layer_params, ck, cv = scanned
         if gather is not None:
             layer_params = gather.layer("layers", layer_params)
-        h, nk, nv = attention_prefill_chunk(
-            layer_params["attn"], cfg, rmsnorm(layer_params["ln1"], xc), ck, cv,
-            pos0, pages=pages,
-        )
-        xc = xc + h
-        xn = rmsnorm(layer_params["ln2"], xc)
-        if cfg.moe is not None:
-            y, _ = moe.moe_block(layer_params["moe"], cfg, xn)
-        else:
-            y = mlp(layer_params["mlp"], cfg, xn)
-        return xc + y, (nk, nv)
+        with jax.named_scope("attn"):
+            h, nk, nv = attention_prefill_chunk(
+                layer_params["attn"], cfg, rmsnorm(layer_params["ln1"], xc),
+                ck, cv, pos0, pages=pages,
+            )
+            xc = xc + h
+        with jax.named_scope("mlp"):
+            xc = xc + _mlp_sublayer(layer_params, cfg, xc)
+        return xc, (nk, nv)
 
-    xf, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    return rmsnorm(params["final_norm"], xf), nk, nv
+    with jax.named_scope("layers"):
+        xf, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache.k, cache.v))
+    with jax.named_scope("final_norm"):
+        return rmsnorm(params["final_norm"], xf), nk, nv
+
+
+def _mlp_sublayer(layer_params, cfg: ModelConfig, x):
+    """ln2 then the layer's MLP (or MoE block), before the residual add."""
+    xn = rmsnorm(layer_params["ln2"], x)
+    if cfg.moe is not None:
+        return moe.moe_block(layer_params["moe"], cfg, xn)[0]
+    return mlp(layer_params["mlp"], cfg, xn)
 
 
 def verify_step(params, serve_table, cfg: ModelConfig, cache: DecodeCache,
@@ -306,30 +315,31 @@ def decode_step(params, serve_table, cfg: ModelConfig, cache: DecodeCache, token
     hot-swap leaves resident requests' tokens identical from the swap
     point."""
     del state_pages
-    if gather is not None:
-        x = gather.rows("embed/table", params["embed"]["table"], token)[:, None, :]
-    else:
-        x = embed(params["embed"], token)[:, None, :]  # (B,1,d)
+    with jax.named_scope("embed"):
+        if gather is not None:
+            x = gather.rows("embed/table", params["embed"]["table"], token)[:, None, :]
+        else:
+            x = embed(params["embed"], token)[:, None, :]  # (B,1,d)
 
     def body(carry, scanned):
         xc = carry
         layer_params, ck, cv = scanned
         if gather is not None:
             layer_params = gather.layer("layers", layer_params)
-        h, nk, nv = attention_decode(
-            layer_params["attn"], cfg, rmsnorm(layer_params["ln1"], xc), ck, cv,
-            pos, pages=pages,
-        )
-        xc = xc + h
-        xn = rmsnorm(layer_params["ln2"], xc)
-        if cfg.moe is not None:
-            y, _ = moe.moe_block(layer_params["moe"], cfg, xn)
-        else:
-            y = mlp(layer_params["mlp"], cfg, xn)
-        return xc + y, (nk, nv)
+        with jax.named_scope("attn"):
+            h, nk, nv = attention_decode(
+                layer_params["attn"], cfg, rmsnorm(layer_params["ln1"], xc),
+                ck, cv, pos, pages=pages,
+            )
+            xc = xc + h
+        with jax.named_scope("mlp"):
+            xc = xc + _mlp_sublayer(layer_params, cfg, xc)
+        return xc, (nk, nv)
 
-    xf, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    h = rmsnorm(params["final_norm"], xf)[:, 0]
+    with jax.named_scope("layers"):
+        xf, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache.k, cache.v))
+    with jax.named_scope("final_norm"):
+        h = rmsnorm(params["final_norm"], xf)[:, 0]
     out = heads.head_topk(
         params["head"], serve_table, cfg, h, k,
         embed_table=params["embed"]["table"], kernel=kernel, mesh=mesh,

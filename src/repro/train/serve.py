@@ -172,6 +172,13 @@ _SALT_ACCEPT = 0x5A17_0003   # speculative accept/reject uniform
 _SALT_RESID = 0x5A17_0004    # speculative residual draw
 
 
+def _fetch_overflow(stats) -> tuple[np.ndarray, np.ndarray]:
+    """The head's per-expert ``dispatched`` and ``overflow`` counts of one
+    step, read to the host in that order."""
+    return (np.asarray(stats["dispatched"], np.int64),
+            np.asarray(stats["overflow"], np.int64))
+
+
 def _uniforms(seed: int, salt: int, m: int, n: int) -> np.ndarray:
     """``n`` iid U[0,1) doubles from a counter-based Philox stream —
     pure host math, a function of (seed, salt, m) alone. The emission
@@ -1398,53 +1405,71 @@ class ServeSession:
         """Expire overdue queued requests, admit into free slots, then run
         ONE jitted decode step over the slot batch (or one speculative
         draft–verify block when ``draft=`` is set). Returns True while
-        work remains."""
-        self._expire_queue()
-        self._admit()
-        if self._draft is not None:
-            return self._step_speculative()
+        work remains.
+
+        Under ``jax.profiler`` the step records host spans: ``serve.step``
+        around all of it, ``serve.admit`` (with one ``serve.prefill`` and
+        its ``serve.prefill.fetch`` per admitted request), then
+        ``serve.decode.dispatch``, ``serve.decode.fetch`` (every
+        device-to-host read of the decode's outputs),
+        ``serve.sample_emit`` and ``serve.adapt``."""
+        with jax.profiler.StepTraceAnnotation("serve.step",
+                                              step_num=self.n_steps):
+            self._expire_queue()
+            with jax.profiler.TraceAnnotation("serve.admit"):
+                self._admit()
+            if self._draft is not None:
+                return self._step_speculative()
+            return self._step_decode()
+
+    def _step_decode(self) -> bool:
         if self._mgr is not None:
             self._prepare_decode_writes()
         act = self.scheduler.active()
         if not act:
             return self.scheduler.has_work()
-        if self._mgr is not None:
-            vals, ids, self._cache, stats = self._decode_fn(
-                self.params, self.table, self._cache,
-                jnp.asarray(self._tok), jnp.asarray(self._pos),
-                jnp.asarray(self._mgr.tables),
-                jnp.asarray(self._mgr.state_pid),
-            )
-        else:
-            vals, ids, self._cache, stats = self._decode_fn(
-                self.params, self.table, self._cache,
-                jnp.asarray(self._tok), jnp.asarray(self._pos),
-            )
-        self.n_steps += 1
-        vals, ids = np.asarray(vals), np.asarray(ids)
-        self._record_overflow(stats)
-        for i, slot in act:
-            if self.scheduler.slots[i] is not slot:
-                continue  # released mid-loop (e.g. cancel from a stream_cb)
-            if not np.isfinite(vals[i]).all() or ids[i, 0] < 0:
-                # quarantine ONLY this slot: per-slot decode math never
-                # mixes rows, so the survivors' outputs are unaffected.
-                # ids[0] < 0 is the masked-NaN signature: XLA's top_k can
-                # sort NaN scores BELOW the finite NEG_INF padding, so a
-                # poisoned row surfaces as all-padding ids rather than
-                # NaN values.
-                self._finish_slot(
-                    i, RequestStatus.FAILED,
-                    "non-finite decode output (slot quarantined)",
+        with jax.profiler.TraceAnnotation("serve.decode.dispatch"):
+            if self._mgr is not None:
+                vals, ids, self._cache, stats = self._decode_fn(
+                    self.params, self.table, self._cache,
+                    jnp.asarray(self._tok), jnp.asarray(self._pos),
+                    jnp.asarray(self._mgr.tables),
+                    jnp.asarray(self._mgr.state_pid),
                 )
-                continue
-            t = self._sample(vals[i], ids[i], slot.req.sampling_params,
-                             slot.n_emitted)
-            self._emit(i, slot, t)
+            else:
+                vals, ids, self._cache, stats = self._decode_fn(
+                    self.params, self.table, self._cache,
+                    jnp.asarray(self._tok), jnp.asarray(self._pos),
+                )
+        self.n_steps += 1
+        with jax.profiler.TraceAnnotation("serve.decode.fetch", arrays=4):
+            vals, ids = np.asarray(vals), np.asarray(ids)
+            disp, over = _fetch_overflow(stats)
+        self._record_overflow(disp, over)
+        with jax.profiler.TraceAnnotation("serve.sample_emit"):
+            for i, slot in act:
+                if self.scheduler.slots[i] is not slot:
+                    continue  # released mid-loop (e.g. cancel from a stream_cb)
+                if not np.isfinite(vals[i]).all() or ids[i, 0] < 0:
+                    # quarantine ONLY this slot: per-slot decode math never
+                    # mixes rows, so the survivors' outputs are unaffected.
+                    # ids[0] < 0 is the masked-NaN signature: XLA's top_k can
+                    # sort NaN scores BELOW the finite NEG_INF padding, so a
+                    # poisoned row surfaces as all-padding ids rather than
+                    # NaN values.
+                    self._finish_slot(
+                        i, RequestStatus.FAILED,
+                        "non-finite decode output (slot quarantined)",
+                    )
+                    continue
+                t = self._sample(vals[i], ids[i], slot.req.sampling_params,
+                                 slot.n_emitted)
+                self._emit(i, slot, t)
         if self._adapt_policy is not None:
             # adaptation swaps strictly BETWEEN steps: the decode above
             # ran to completion on the old table version
-            self._maybe_adapt()
+            with jax.profiler.TraceAnnotation("serve.adapt"):
+                self._maybe_adapt()
         return self.scheduler.has_work()
 
     def _step_speculative(self) -> bool:
@@ -1527,7 +1552,7 @@ class ServeSession:
                 jnp.asarray(base_pos))
         self.n_steps += 1
         vvals, vids = np.asarray(vvals), np.asarray(vids)
-        self._record_overflow(stats)
+        self._record_overflow(*_fetch_overflow(stats))
 
         # -- host-side exact acceptance -----------------------------------
         emitted: dict = {}
@@ -1685,7 +1710,8 @@ class ServeSession:
 
     def stats(self) -> dict:
         """Host-side counters snapshot: queue/slot occupancy, per-outcome
-        request counts, shed count, per-expert dispatch/overflow totals
+        request counts, shed count, prefill chunks run on any chunked
+        path (``prefill_chunks``), per-expert dispatch/overflow totals
         AND the step-stamped window over them (``*_window`` keys with
         ``window_start_step``/``window_end_step`` stamps — what
         :meth:`traffic_profile` consumes), the circuit-breaker state,
@@ -1703,6 +1729,7 @@ class ServeSession:
             "n_admitted": self.scheduler.n_admitted,
             "n_released": self.scheduler.n_released,
             "n_steps": self.n_steps,
+            "prefill_chunks": self._n_prefill_chunks,
             "n_queued": len(self.scheduler.queue),
             "queue_depth": len(self.scheduler.queue),
             "n_active": len(self.scheduler.active()),
@@ -1827,9 +1854,9 @@ class ServeSession:
                 "exceeded while queued",
             )
 
-    def _record_overflow(self, stats) -> None:
-        disp = np.asarray(stats["dispatched"], np.int64)
-        over = np.asarray(stats["overflow"], np.int64)
+    def _record_overflow(self, disp: np.ndarray, over: np.ndarray) -> None:
+        """Account one step's per-expert dispatch/overflow counts (host
+        arrays, see :func:`_fetch_overflow`) and run the breaker."""
         if self._expert_dispatched is None \
                 or self._expert_dispatched.shape != disp.shape:
             # first step on this table version (swap_table resets the
@@ -1906,7 +1933,15 @@ class ServeSession:
                     [prompt, np.asarray(req.out_tokens[:-1], np.int32)])
             else:
                 toks = prompt
-            out = self._prefill_into_slot(toks, i, sp.priority)
+            with jax.profiler.TraceAnnotation(
+                    "serve.prefill", slot=i, tokens=len(toks)) as span:
+                chunks0 = self._n_prefill_chunks
+                out = self._prefill_into_slot(toks, i, sp.priority)
+                span.set_metadata(chunks=self._n_prefill_chunks - chunks0)
+                if out is not None:
+                    vals, ids, pending = out
+                    with jax.profiler.TraceAnnotation("serve.prefill.fetch"):
+                        vals, ids = np.asarray(vals), np.asarray(ids)
             if out is None:
                 # paged arena exhausted with nothing preemptible below
                 # this priority: requeue at the FRONT and wait for the
@@ -1921,8 +1956,6 @@ class ServeSession:
                     "page arena exhausted with no resident to wait for",
                 )
                 continue
-            vals, ids, pending = out
-            vals, ids = np.asarray(vals), np.asarray(ids)
             if not np.isfinite(vals[0]).all() or ids[0, 0] < 0:
                 # quarantine BEFORE admission: the slot stays free and
                 # its poisoned cache rows are scrubbed so the next
@@ -1996,6 +2029,7 @@ class ServeSession:
                     self.params, self.table, row, jnp.asarray(buf[None]),
                     lo, len(tail),
                 )
+                self._n_prefill_chunks += 1
             self._cache = self._insert_fn(self._cache, row, i)
             return vals, ids, pending
         # paged chunked prefill, straight into the shared arena

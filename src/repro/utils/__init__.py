@@ -10,7 +10,6 @@ from repro.utils.tree import (
     unflatten_dict,
 )
 from repro.utils.logging import get_logger
-from repro.utils.timing import Timer
 
 __all__ = [
     "tree_size",
@@ -23,5 +22,4 @@ __all__ = [
     "flatten_dict",
     "unflatten_dict",
     "get_logger",
-    "Timer",
 ]
