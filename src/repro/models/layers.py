@@ -231,8 +231,38 @@ def attention_block(params, cfg: ModelConfig, x: jax.Array, positions, *, causal
     return out, (k, v)
 
 
+def _kv_write(cache, new, layer, *idx):
+    """Scatter ``new`` into one layer's K/V at the integer-array index
+    ``idx`` (its trailing dims follow). ``layer`` None: ``cache`` is that
+    layer's slab; else the stacked ``(L, ...)`` cache, written in place
+    at ``[layer, *idx]``."""
+    if layer is not None:
+        idx = (layer,) + idx
+    return cache.at[idx].set(new.astype(cache.dtype))
+
+
+def _kv_write_block(cache, new, layer, pos):
+    """Write the ``(B, C, KV, dh)`` block ``new`` at sequence position
+    ``pos`` of one layer's contiguous K/V (``layer`` as in
+    :func:`_kv_write`)."""
+    starts = (0, pos, 0, 0)
+    if layer is not None:
+        new, starts = new[None], (layer,) + starts
+    return jax.lax.dynamic_update_slice(cache, new.astype(cache.dtype), starts)
+
+
+def _kv_view(cache, layer, pages):
+    """One layer's logical ``(B, S, KV, dh)`` K/V: the slab itself, its
+    row of the stacked cache, or (paged) its pages gathered per slot."""
+    if pages is None:
+        return cache if layer is None else cache[layer]
+    n_pg, ps = pages.shape[1], cache.shape[-3]
+    view = cache[pages] if layer is None else cache[layer, pages]
+    return view.reshape(pages.shape[0], n_pg * ps, *cache.shape[-2:])
+
+
 def attention_decode(params, cfg: ModelConfig, x, cache_k, cache_v, pos,
-                     pages=None):
+                     pages=None, layer=None):
     """One-token decode against a (B, S_cache, KV, dh) cache.
 
     x: (B, 1, d); pos: scalar int (one shared position; cache rows > pos
@@ -240,6 +270,11 @@ def attention_decode(params, cfg: ModelConfig, x, cache_k, cache_v, pos,
     continuous-batching case where every slot decodes at its own sequence
     length. Returns (out (B,1,d), new_k, new_v) with the caches updated in
     place at ``pos`` (row b at ``pos[b]`` for the vector form).
+
+    ``layer`` (a traced layer index) makes ``cache_k``/``cache_v`` the
+    STACKED ``(L, ...)`` caches: only the new rows of layer ``layer`` are
+    written, and attention reads that layer's slab straight from the
+    stack — a decode scan that carries the stack never rebuilds it.
 
     ``pages`` switches to the PAGED cache layout: ``cache_k``/``cache_v``
     are then ``(n_pages, page_size, KV, dh)`` arenas and ``pages`` is the
@@ -258,29 +293,23 @@ def attention_decode(params, cfg: ModelConfig, x, cache_k, cache_v, pos,
     per_row = pos.ndim == 1
     pos_b = pos if per_row else jnp.full((B,), pos)  # (B,)
     q, k_new, v_new = _project_qkv(params, cfg, x, pos_b[:, None])
-    if pages is not None:
-        ps = cache_k.shape[1]
-        rows = jnp.arange(B)
-        with jax.named_scope("kv_write"):
+    with jax.named_scope("kv_write"):
+        if pages is not None:
+            ps = cache_k.shape[-3]
+            rows = jnp.arange(B)
             pid = pages[rows, pos_b // ps]  # (B,) write page per row
             off = pos_b % ps
-            cache_k = cache_k.at[pid, off].set(k_new[:, 0].astype(cache_k.dtype))
-            cache_v = cache_v.at[pid, off].set(v_new[:, 0].astype(cache_v.dtype))
-        n_pg = pages.shape[1]
-        KVh, dh_ = cache_k.shape[2], cache_k.shape[3]
-        view_k = cache_k[pages].reshape(B, n_pg * ps, KVh, dh_)
-        view_v = cache_v[pages].reshape(B, n_pg * ps, KVh, dh_)
-    elif per_row:
-        rows = jnp.arange(B)
-        with jax.named_scope("kv_write"):
-            cache_k = cache_k.at[rows, pos_b].set(k_new[:, 0].astype(cache_k.dtype))
-            cache_v = cache_v.at[rows, pos_b].set(v_new[:, 0].astype(cache_v.dtype))
-        view_k, view_v = cache_k, cache_v
-    else:
-        with jax.named_scope("kv_write"):
-            cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k_new.astype(cache_k.dtype), pos, axis=1)
-            cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v_new.astype(cache_v.dtype), pos, axis=1)
-        view_k, view_v = cache_k, cache_v
+            cache_k = _kv_write(cache_k, k_new[:, 0], layer, pid, off)
+            cache_v = _kv_write(cache_v, v_new[:, 0], layer, pid, off)
+        elif per_row:
+            rows = jnp.arange(B)
+            cache_k = _kv_write(cache_k, k_new[:, 0], layer, rows, pos_b)
+            cache_v = _kv_write(cache_v, v_new[:, 0], layer, rows, pos_b)
+        else:
+            cache_k = _kv_write_block(cache_k, k_new, layer, pos)
+            cache_v = _kv_write_block(cache_v, v_new, layer, pos)
+    view_k = _kv_view(cache_k, layer, pages)
+    view_v = _kv_view(cache_v, layer, pages)
     from repro.distributed.hints import BATCH, constrain
 
     H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -303,7 +332,7 @@ def attention_decode(params, cfg: ModelConfig, x, cache_k, cache_v, pos,
 
 
 def attention_prefill_chunk(params, cfg: ModelConfig, x, cache_k, cache_v, pos0,
-                            pages=None):
+                            pages=None, layer=None):
     """Cache-context chunked prefill: C new tokens against a partially
     filled (B, S_max, KV, dh) cache.
 
@@ -317,6 +346,9 @@ def attention_prefill_chunk(params, cfg: ModelConfig, x, cache_k, cache_v, pos0,
     full-prompt prefill (masked positions contribute exact zeros to the
     softmax). Padding rows at the chunk tail write K/V at positions that
     stay masked until a later real token overwrites them.
+
+    ``layer`` makes the caches the stacked ``(L, ...)`` ones, written and
+    read at that layer in place (see :func:`attention_decode`).
 
     ``pages`` switches to the PAGED layout (see
     :func:`attention_decode`): ``cache_k``/``cache_v`` are
@@ -334,30 +366,22 @@ def attention_prefill_chunk(params, cfg: ModelConfig, x, cache_k, cache_v, pos0,
     positions = jnp.broadcast_to(
         (pos0[:, None] if per_row else pos0) + jnp.arange(C)[None, :], (B, C))
     q, k_new, v_new = _project_qkv(params, cfg, x, positions)
-    if pages is not None:
-        ps = cache_k.shape[1]
-        with jax.named_scope("kv_write"):
+    with jax.named_scope("kv_write"):
+        if pages is not None:
+            ps = cache_k.shape[-3]
             pid = jnp.take_along_axis(pages, positions // ps, axis=1)  # (B, C)
             off = positions % ps
-            cache_k = cache_k.at[pid, off].set(k_new.astype(cache_k.dtype))
-            cache_v = cache_v.at[pid, off].set(v_new.astype(cache_v.dtype))
-        n_pg = pages.shape[1]
-        KVh, dh_ = cache_k.shape[2], cache_k.shape[3]
-        view_k = cache_k[pages].reshape(B, n_pg * ps, KVh, dh_)
-        view_v = cache_v[pages].reshape(B, n_pg * ps, KVh, dh_)
-    elif per_row:
-        rows = jnp.arange(B)[:, None]
-        with jax.named_scope("kv_write"):
-            cache_k = cache_k.at[rows, positions].set(k_new.astype(cache_k.dtype))
-            cache_v = cache_v.at[rows, positions].set(v_new.astype(cache_v.dtype))
-        view_k, view_v = cache_k, cache_v
-    else:
-        with jax.named_scope("kv_write"):
-            cache_k = jax.lax.dynamic_update_slice_in_dim(
-                cache_k, k_new.astype(cache_k.dtype), pos0, axis=1)
-            cache_v = jax.lax.dynamic_update_slice_in_dim(
-                cache_v, v_new.astype(cache_v.dtype), pos0, axis=1)
-        view_k, view_v = cache_k, cache_v
+            cache_k = _kv_write(cache_k, k_new, layer, pid, off)
+            cache_v = _kv_write(cache_v, v_new, layer, pid, off)
+        elif per_row:
+            rows = jnp.arange(B)[:, None]
+            cache_k = _kv_write(cache_k, k_new, layer, rows, positions)
+            cache_v = _kv_write(cache_v, v_new, layer, rows, positions)
+        else:
+            cache_k = _kv_write_block(cache_k, k_new, layer, pos0)
+            cache_v = _kv_write_block(cache_v, v_new, layer, pos0)
+    view_k = _kv_view(cache_k, layer, pages)
+    view_v = _kv_view(cache_v, layer, pages)
     H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     G = H // KV
     S = view_k.shape[1]

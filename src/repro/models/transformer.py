@@ -29,7 +29,13 @@ from repro.models.layers import (
 
 
 class DecodeCache(NamedTuple):
-    k: jax.Array  # (L, B, S_max, KV, dh)
+    """The stacked per-layer K/V cache. Every step that extends it
+    (:func:`decode_step`, :func:`prefill_chunk`, :func:`verify_step`)
+    carries it through the layer scan and writes only the new rows, so a
+    caller that donates it (``ServeSession`` does, to every program whose
+    result replaces its cache) has it updated in place, never rebuilt or
+    copied."""
+    k: jax.Array  # (L, B, S_max, KV, dh); paged: (L, n_pages, page_size, KV, dh)
     v: jax.Array
 
 
@@ -230,26 +236,41 @@ def _chunk_hidden(params, cfg: ModelConfig, cache: DecodeCache, tokens, pos0,
             x = gather.rows("embed/table", params["embed"]["table"], tokens)
         else:
             x = embed(params["embed"], tokens)  # (B, C, d)
+    xf, nk, nv = _cache_scan(
+        params, cfg, cache, x, gather,
+        lambda ap, xn, ck, cv, layer: attention_prefill_chunk(
+            ap, cfg, xn, ck, cv, pos0, pages=pages, layer=layer))
+    with jax.named_scope("final_norm"):
+        return rmsnorm(params["final_norm"], xf), nk, nv
+
+
+def _cache_scan(params, cfg: ModelConfig, cache: DecodeCache, x, gather,
+                attend):
+    """The layer scan of every cache-updating step: the stacked cache rides
+    in the carry beside the residual, and ``attend(attn_params, x_normed,
+    cache_k, cache_v, layer)`` writes only its layer's new rows into it
+    and reads that layer's slab in place. Scanning the cache as xs/ys
+    instead would rebuild the whole stack every call. Returns (x,
+    cache_k, cache_v)."""
 
     def body(carry, scanned):
-        xc = carry
-        layer_params, ck, cv = scanned
+        xc, ck, cv = carry
+        layer_params, layer = scanned
         if gather is not None:
             layer_params = gather.layer("layers", layer_params)
         with jax.named_scope("attn"):
-            h, nk, nv = attention_prefill_chunk(
-                layer_params["attn"], cfg, rmsnorm(layer_params["ln1"], xc),
-                ck, cv, pos0, pages=pages,
-            )
+            h, ck, cv = attend(layer_params["attn"],
+                               rmsnorm(layer_params["ln1"], xc), ck, cv, layer)
             xc = xc + h
         with jax.named_scope("mlp"):
             xc = xc + _mlp_sublayer(layer_params, cfg, xc)
-        return xc, (nk, nv)
+        return (xc, ck, cv), None
 
     with jax.named_scope("layers"):
-        xf, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    with jax.named_scope("final_norm"):
-        return rmsnorm(params["final_norm"], xf), nk, nv
+        (xf, nk, nv), _ = jax.lax.scan(
+            body, (x, cache.k, cache.v),
+            (params["layers"], jnp.arange(cache.k.shape[0], dtype=jnp.int32)))
+    return xf, nk, nv
 
 
 def _mlp_sublayer(layer_params, cfg: ModelConfig, x):
@@ -313,7 +334,13 @@ def decode_step(params, serve_table, cfg: ModelConfig, cache: DecodeCache, token
     versioned ``TableResource`` (unwrapped once in
     ``heads.head_topk``) — the backbone never reads it, which is why a
     hot-swap leaves resident requests' tokens identical from the swap
-    point."""
+    point.
+
+    ``new_cache`` is ``cache`` with one row per slot and layer written:
+    the stacked cache rides in the layer scan's carry (:func:`_cache_scan`)
+    and each layer attends over its own slab in place. Donated, as the
+    serving session donates it, the step writes those rows into the
+    buffer it was given and moves no other cache byte but the reads."""
     del state_pages
     with jax.named_scope("embed"):
         if gather is not None:
@@ -321,23 +348,10 @@ def decode_step(params, serve_table, cfg: ModelConfig, cache: DecodeCache, token
         else:
             x = embed(params["embed"], token)[:, None, :]  # (B,1,d)
 
-    def body(carry, scanned):
-        xc = carry
-        layer_params, ck, cv = scanned
-        if gather is not None:
-            layer_params = gather.layer("layers", layer_params)
-        with jax.named_scope("attn"):
-            h, nk, nv = attention_decode(
-                layer_params["attn"], cfg, rmsnorm(layer_params["ln1"], xc),
-                ck, cv, pos, pages=pages,
-            )
-            xc = xc + h
-        with jax.named_scope("mlp"):
-            xc = xc + _mlp_sublayer(layer_params, cfg, xc)
-        return xc, (nk, nv)
-
-    with jax.named_scope("layers"):
-        xf, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache.k, cache.v))
+    xf, nk, nv = _cache_scan(
+        params, cfg, cache, x, gather,
+        lambda ap, xn, ck, cv, layer: attention_decode(
+            ap, cfg, xn, ck, cv, pos, pages=pages, layer=layer))
     with jax.named_scope("final_norm"):
         h = rmsnorm(params["final_norm"], xf)[:, 0]
     out = heads.head_topk(

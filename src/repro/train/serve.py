@@ -831,10 +831,12 @@ class ServeSession:
                     lambda sh, kv: sh if kv else sh.at[:, pid].set(0),
                     c, kvl))
 
-            self._copy_page_fn = jax.jit(_copy_page)
-            self._zero_kv_page_fn = jax.jit(_zero_kv_page)
-            self._copy_state_page_fn = jax.jit(_copy_state_page)
-            self._zero_state_page_fn = jax.jit(_zero_state_page)
+            self._copy_page_fn = jax.jit(_copy_page, donate_argnums=0)
+            self._zero_kv_page_fn = jax.jit(_zero_kv_page, donate_argnums=0)
+            self._copy_state_page_fn = jax.jit(_copy_state_page,
+                                               donate_argnums=0)
+            self._zero_state_page_fn = jax.jit(_zero_state_page,
+                                               donate_argnums=0)
 
             def _insert_paged(c, row, page_row, state_pid):
                 # Scatter a whole-prompt (·, 1, S, ·) prefilled cache into
@@ -850,7 +852,7 @@ class ServeSession:
 
                 return self._pin(jax.tree.map(put, c, row, kvl))
 
-            self._insert_paged_fn = jax.jit(_insert_paged)
+            self._insert_paged_fn = jax.jit(_insert_paged, donate_argnums=0)
         else:
             def _insert(shared, row, slot):
                 # Write a (·, 1, S, ·) prefilled request cache into slot
@@ -866,7 +868,7 @@ class ServeSession:
 
                 return self._pin(jax.tree.map(put, shared, row, axes))
 
-            self._insert_fn = jax.jit(_insert)
+            self._insert_fn = jax.jit(_insert, donate_argnums=0)
 
             def _scrub(shared, slot):
                 # Zero EVERY cache row of slot ``slot``. Run after a FAILED
@@ -877,7 +879,7 @@ class ServeSession:
                 return self._pin(
                     jax.tree.map(lambda sh: sh.at[:, slot].set(0), shared))
 
-            self._scrub_fn = jax.jit(_scrub)
+            self._scrub_fn = jax.jit(_scrub, donate_argnums=0)
 
     # -- versioned table resource -------------------------------------------
 
@@ -952,7 +954,10 @@ class ServeSession:
                 vals, ids, c, stats = out
                 return vals, ids, self._pin(c), stats
 
-        self._decode_fn = jax.jit(_decode)
+        # Every program whose result replaces ``self._cache`` is handed it
+        # donated: the step then writes its rows into the buffer it was
+        # given, and no second cache is ever allocated or copied.
+        self._decode_fn = jax.jit(_decode, donate_argnums=2)
 
         if self._draft is None:
             return
@@ -983,7 +988,7 @@ class ServeSession:
                 vals, ids, c, stats = out
                 return vals, ids, self._pin(c), stats
 
-        self._verify_fn = jax.jit(_verify)
+        self._verify_fn = jax.jit(_verify, donate_argnums=2)
 
         if not bundle.verify_needs_state_commit:
             return
@@ -999,7 +1004,7 @@ class ServeSession:
                     self._pin_p(p), c, toks, pos0, nv, gather=self._gather,
                 ))
 
-        self._commit_fn = jax.jit(_commit)
+        self._commit_fn = jax.jit(_commit, donate_argnums=1)
 
     def _build_prefill_fns(self) -> None:
         """(Re)build the jitted prefill closures. Like the decode step,
@@ -1042,7 +1047,11 @@ class ServeSession:
                             x, self._row_sharding), c)
                 return vals, ids, c
 
-        self._chunk_fn = jax.jit(_chunk)
+        # the paged chunk updates the shared arena, donated like the decode
+        # step's cache; a contiguous chunk's row starts from the kept
+        # ``_row_zero``, so it is never donated
+        self._chunk_fn = jax.jit(
+            _chunk, donate_argnums=2 if self._mgr is not None else ())
 
     def _build_draft_fns(self) -> None:
         """Jitted closures over the draft model, built ONCE at init (the
