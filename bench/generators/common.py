@@ -9,16 +9,22 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from statistics import NormalDist
+from typing import Optional
 
 import numpy as np
 
 
 @dataclass(frozen=True)
 class Spec:
-    """One request as the generator offers it; every request is greedy."""
+    """One request as the generator offers it: greedy unless it sets a
+    ``temperature`` (then sampled from the head's ``top_k`` candidates
+    with the sampling stream ``seed``)."""
 
     prompt: np.ndarray          # (S,) int32 token ids
     max_new_tokens: int
+    temperature: float = 0.0
+    top_k: Optional[int] = None
+    seed: int = 0
 
 
 def rng_for(seed: int, *salt: int) -> np.random.Generator:

@@ -17,6 +17,7 @@ import collections
 import gc
 import importlib
 import json
+import math
 import os
 import statistics
 import time
@@ -248,6 +249,7 @@ class Tracked:
     req: object
     sent: float           # host clock
     times: list = field(default_factory=list)
+    due: Optional[float] = None   # host clock; an open loop's schedule
 
 
 @dataclass
@@ -261,11 +263,17 @@ class Record:
     compiles_in_window: int = 0
     setup_s: float = 0.0
     warm_s: float = 0.0        # seconds the warm-up took, part of set-up
+    queue_open: Optional[int] = None    # an open loop's queue as the window opens ...
+    queue_close: Optional[int] = None   # ... and as it closes
 
 
 class Runner:
     """Submits the traffic and steps the session; records when every
-    token of every request arrived (``stream_cb``)."""
+    token of every request arrived (``stream_cb``). ``clock`` and
+    ``sleep`` are the host's, and a test may put its own in their place."""
+
+    clock = staticmethod(time.perf_counter)
+    sleep = staticmethod(time.sleep)
 
     def __init__(self, cell: Cell, seed: int, seconds: float):
         self.cell = cell
@@ -277,17 +285,19 @@ class Runner:
     def on_token(self, req, token):
         t = self.by_req.get(id(req))
         if t is not None:
-            t.times.append(time.perf_counter())
+            t.times.append(self.clock())
 
-    def _submit(self, spec, rec: Record):
+    def _submit(self, spec, rec: Record, due: Optional[float] = None):
         from repro.train import Request, SamplingParams
 
         import jax
 
-        sp = SamplingParams(max_new_tokens=spec.max_new_tokens, temperature=0.0)
+        sp = SamplingParams(max_new_tokens=spec.max_new_tokens,
+                            temperature=spec.temperature, top_k=spec.top_k,
+                            seed=spec.seed)
         req = Request(prompt=spec.prompt, sampling=sp)
         with jax.profiler.TraceAnnotation("bench.submit"):
-            tr = Tracked(spec, req, time.perf_counter())
+            tr = Tracked(spec, req, self.clock(), due=due)
             self.by_req[id(req)] = tr
             self.server.session.submit(req)
         rec.tracked.append(tr)
@@ -328,15 +338,20 @@ class Runner:
     def run(self, log: CompileLog, trace_dir: Optional[str], t_start: float) -> Record:
         """Set-up already built ``self.server``; warm up, open the window,
         drive it, close. A traced run records a window of at most
-        ``TRACE_S`` seconds, all of it traced."""
+        ``TRACE_S`` seconds, all of it traced. The traffic's generator
+        picks the driver: one that declares ``LOOP = "open"`` yields
+        ``(due_s, Spec)`` on a schedule; any other, a backlog of specs."""
         cell, rec = self.cell, Record()
         seconds = min(self.seconds, TRACE_S) if trace_dir else self.seconds
-        specs = cell.generator.generate(cell.traffic, self.seed,
-                                        cell.sizes["vocab"], seconds)
+        gen = cell.generator
+        items = gen.generate(cell.traffic, self.seed, cell.sizes["vocab"], seconds)
         t = time.perf_counter()
         self.warm_up(self.seed ^ 0x5EED)
         rec.warm_s = time.perf_counter() - t
-        self._run_backlog(specs, rec, log, trace_dir, t_start, seconds)
+        if getattr(gen, "LOOP", None) == "open":
+            self._run_open(items, rec, log, trace_dir, t_start, seconds)
+        else:
+            self._run_backlog(items, rec, log, trace_dir, t_start, seconds)
         return rec
 
     def _top_up(self, specs, rec):
@@ -361,6 +376,51 @@ class Runner:
         rec.t_close = time.perf_counter()
         self._stop_trace(trace_dir)
         rec.compiles_in_window = log.total - n0
+
+    def _run_open(self, arrivals, rec, log, trace_dir, t_start, seconds):
+        """An open loop. Before each step, every request whose due time
+        has come is submitted; with nothing to serve, the loop sleeps until
+        the next one is due. First ``warm_s`` seconds of the arrival
+        process (due times before 0, set-up), then the window, whose own
+        arrivals are due from its open on."""
+        sched = self.server.session.scheduler
+        arrivals = iter(arrivals)
+        t_warm = self.clock() + float(self.cell.traffic["warm_s"])
+        nxt = self._drive(arrivals, next(arrivals, None), t_warm, 0.0, t_warm,
+                          rec, False)
+        n0 = log.total                                   # the warm stretch is set-up
+        rec.setup_s = self.clock() - t_start
+        self._start_trace(trace_dir)
+        rec.t_open = self.clock()
+        rec.queue_open = len(sched.queue)
+        self._drive(arrivals, nxt, rec.t_open, math.inf, rec.t_open + seconds,
+                    rec, trace_dir is not None)
+        rec.t_close = self.clock()
+        rec.queue_close = len(sched.queue)
+        self._stop_trace(trace_dir)
+        rec.compiles_in_window = log.total - n0
+
+    def _drive(self, arrivals, nxt, base, stop, end, rec, detail):
+        """Serve until the clock reaches ``end``: submit each arrival
+        ``(due_s, spec)`` with ``due_s < stop`` once ``base + due_s`` has
+        come, then step, or sleep while there is nothing to serve. Returns
+        the first arrival not submitted."""
+        import jax
+
+        sched = self.server.session.scheduler
+        while True:
+            now = self.clock()
+            if now >= end:
+                return nxt
+            while nxt is not None and nxt[0] < stop and base + nxt[0] <= now:
+                self._submit(nxt[1], rec, due=base + nxt[0])
+                nxt = next(arrivals, None)
+            if sched.has_work():
+                self._step(rec, detail)
+                continue
+            wake = base + nxt[0] if nxt is not None and nxt[0] < stop else end
+            with jax.profiler.TraceAnnotation("bench.idle"):
+                self.sleep(max(0.0, min(wake, end) - now))
 
     def _start_trace(self, trace_dir):
         import jax
@@ -391,8 +451,19 @@ def quantile(xs, q: float) -> float:
     return float(cuts[int(round(q * 100)) - 1])
 
 
+def ttft_s(rec: Record) -> list:
+    """Time to first token, from the due time, of every request due in the
+    window; one with no token by the close counts as close - due. Empty
+    in a closed loop, whose requests have no due time."""
+    return [min(t.times[0] if t.times else rec.t_close, rec.t_close) - t.due
+            for t in rec.tracked
+            if t.due is not None and rec.t_open <= t.due < rec.t_close]
+
+
 def end_to_end(rec: Record, metric_names) -> dict:
-    """Every end-to-end metric of the cell, from host-clock timestamps."""
+    """Every end-to-end metric of the cell, from host-clock timestamps. A
+    name ``<quantity>.<part>`` is that quantity under a bound of its own,
+    for the cells it lists (``tpot_p50_ms.open``: the open loop's pace)."""
     window = rec.t_close - rec.t_open
     out = {}
     toks = sum(1 for t in rec.tracked for x in t.times
@@ -400,14 +471,16 @@ def end_to_end(rec: Record, metric_names) -> dict:
     done = [t for t in rec.tracked if t.req.status.name == "COMPLETED"
             and len(t.times) > 1 and rec.t_open <= t.times[-1] < rec.t_close]
     tpot = [(t.times[-1] - t.times[0]) / (len(t.times) - 1) for t in done]
+    ttft = ttft_s(rec)
     values = {
         "output_tokens_per_s": (toks / window, "tokens/s"),
         "tpot_p50_ms": (1e3 * quantile(tpot, 0.50) if tpot else None, "ms"),
         "tpot_p95_ms": (1e3 * quantile(tpot, 0.95) if tpot else None, "ms"),
+        "ttft_p50_ms": (1e3 * quantile(ttft, 0.50) if ttft else None, "ms"),
         "setup_s": (rec.setup_s, "s"),
     }
     for name in metric_names:
-        v, unit = values[name]
+        v, unit = values[name.split(".")[0]]
         if v is not None:
             out[name] = {"value": v, "unit": unit}
     return out
@@ -424,9 +497,12 @@ def attempted_failed(rec: Record) -> tuple[int, int]:
 # ---------------------------------------------------------------------------
 
 def pick_sample(rec: Record, seed: int):
-    """Finished requests, drawn from the seed, the longest first, until ``SAMPLE_TOKENS`` served tokens in ``SAMPLE_MIN_REQUESTS``
-    requests or more, or ``SAMPLE_REQUESTS`` requests."""
-    done = [t for t in rec.tracked if t.req.status.name == "COMPLETED"]
+    """Finished greedy requests, drawn from the seed, the longest first,
+    until ``SAMPLE_TOKENS`` served tokens in ``SAMPLE_MIN_REQUESTS``
+    requests or more, or ``SAMPLE_REQUESTS`` requests. A sampled token is
+    not the reference's best, so sampled requests are not drawn."""
+    done = [t for t in rec.tracked if t.req.status.name == "COMPLETED"
+            and t.spec.temperature <= 0]
     if not done:
         return []
     longest = max(done, key=lambda t: len(t.req.out_tokens))

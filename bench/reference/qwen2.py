@@ -116,3 +116,11 @@ def token_flops(s: dict, ctx: int) -> int:
     """Model operations of one token with ``ctx`` positions in view."""
     from work import dense_token_flops
     return dense_token_flops(s["d"], s["H"], s["KV"], s["hd"], s["ff"], s["L"], ctx)
+
+
+def token_bytes(s: dict, ctx: int) -> int:
+    """Least bytes of one slot's cache that a decode token moves: the
+    bfloat16 keys and values of the ``ctx`` positions in view read (its
+    own among them) and its own row written, in every layer."""
+    row = 2 * s["KV"] * s["hd"] * 2
+    return s["L"] * row * (ctx + 1)

@@ -94,6 +94,14 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, bench: dict,
         log(f"DS head kernel at the call site of batch {b}: {name}")
     log(f"set-up: process start to session built {built_s:.3f} s, warm-up "
         f"{rec.warm_s:.3f} s, then to the window {rec.setup_s - built_s - rec.warm_s:.3f} s")
+    log(f"window {rec.t_close - rec.t_open:.3f} s; requests queued at its open "
+        f"{rec.queue_open}, at its close {rec.queue_close}")
+    ttft = harness.ttft_s(rec)
+    if ttft:
+        q = ", ".join(f"p{round(100 * p)} {1e3 * harness.quantile(ttft, p):.3f}"
+                      for p in (0.5, 0.9, 0.95, 0.99))
+        log(f"ttft ms over the {len(ttft)} requests due in the window: {q}, "
+            f"max {1e3 * max(ttft):.3f}")
     log(f"V_pad {server.v_pad}; setup_s {rec.setup_s:.3f}; compiles "
         f"{clog.total} ({clog.secs:.3f} s), persistent-cache hits "
         f"{clog.cache_hits}; compiles inside the window {rec.compiles_in_window}")
@@ -113,7 +121,9 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, bench: dict,
         for m in bench["per_layer"]:
             if cell.name not in m.get("workloads", [cell.name]):
                 continue
-            v = importlib.import_module(f"metrics.{m['name']}").read(r)
+            # ``<name>.<part>``, a quantity split by the end-to-end metric it
+            # moves in some cells, reads with ``metrics/<name>.py``
+            v = importlib.import_module(f"metrics.{m['name'].split('.')[0]}").read(r)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         breakdown = {"device_ops": red.device_ops, "idle_gaps": red.idle_gaps}
@@ -129,16 +139,16 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, bench: dict,
     hs = harness.reference_hidden(cell, seed, sample)
     gaps = harness.logit_gaps(cell, seed, sample, hs)
     n_tok = int(sum(g.size for g in gaps))
-    widest = float(max(g.max() for g in gaps if g.size)) if n_tok else None
+    widest, mean = _widest_mean(gaps)
     log(f"reference: {len(sample)} requests, {n_tok} served tokens, "
-        f"widest gap {widest}, {time.perf_counter() - t_ref:.3f} s")
+        f"widest gap {widest}, mean gap {mean}, {time.perf_counter() - t_ref:.3f} s")
     if control:
         served = harness.control_served(cell, seed, sample)
         gaps = harness.logit_gaps(cell, seed, sample, hs, served=served)
-        widest = float(max(g.max() for g in gaps if g.size)) if n_tok else None
+        widest, mean = _widest_mean(gaps)
         agree = np.mean(np.concatenate([c == o for c, (_, o) in zip(served, sample)]))
         log(f"float8 control in the served tokens' place: widest gap {widest}, "
-            f"the same first token at {agree:.4f} of the positions")
+            f"mean gap {mean}, the same first token at {agree:.4f} of the positions")
 
     checks = {
         "logit_gap": {"value": widest,
@@ -159,6 +169,14 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, bench: dict,
         result["breakdown"] = breakdown
     result["checks"] = checks
     return result
+
+
+def _widest_mean(gaps) -> tuple:
+    """The widest and the mean gap over every compared token (None if none)."""
+    flat = np.concatenate([g for g in gaps if g.size]) if gaps else np.zeros(0)
+    if not flat.size:
+        return None, None
+    return float(flat.max()), float(flat.mean())
 
 
 def reduce_trace(trace_dir: str):

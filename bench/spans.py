@@ -460,7 +460,7 @@ def measure(cell, seed: int, seconds: float, peak: dict) -> dict:
              if cell.name in m.get("workloads", [cell.name])] + list(METRICS)
     metrics = {}
     for n in names:
-        v = importlib.import_module(f"metrics.{n}").read(r)
+        v = importlib.import_module(f"metrics.{n.split('.')[0]}").read(r)
         if v is not None:
             metrics[n] = v
     harness.free_program_state(server)

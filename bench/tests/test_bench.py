@@ -21,29 +21,37 @@ def _traffic(name):
         return json.load(f)
 
 
-@pytest.mark.parametrize("mix", ["decode_backlog"])
-def test_generator_is_seed_determined(mix):
+def _specs(t, seed, vocab, n):
+    """The first ``n`` requests of a mix's window (an open loop's from
+    its due time 0 on)."""
     import itertools
     import importlib
 
-    t = _traffic(mix)
     gen = importlib.import_module(f"generators.{t['kind']}")
-    take = lambda seed: list(itertools.islice(gen.generate(t, seed, 50000, 10.0), 300))
+    items = gen.generate(t, seed, vocab, 60.0)
+    if getattr(gen, "LOOP", None) == "open":
+        items = (spec for due, spec in items if due >= 0)
+    return list(itertools.islice(items, n))
+
+
+MIXES = ["decode_backlog", "chat_bursty"]
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_generator_is_seed_determined(mix):
+    t = _traffic(mix)
+    take = lambda seed: _specs(t, seed, 50000, 300)
     a, b, c = take(BIG_SEED), take(BIG_SEED), take(BIG_SEED + 1)
     assert all(np.array_equal(x.prompt, y.prompt)
                and x.max_new_tokens == y.max_new_tokens for x, y in zip(a, b))
     assert any(not np.array_equal(x.prompt, y.prompt) for x, y in zip(a, c))
 
 
-@pytest.mark.parametrize("mix", ["decode_backlog"])
+@pytest.mark.parametrize("mix", MIXES)
 def test_lengths_are_clipped_and_the_same_multiset_for_every_seed(mix):
-    import itertools
-    import importlib
-
     t = _traffic(mix)
-    gen = importlib.import_module(f"generators.{t['kind']}")
     block = t["block"]
-    take = lambda seed: list(itertools.islice(gen.generate(t, seed, 1000, 60.0), block))
+    take = lambda seed: _specs(t, seed, 1000, block)
     for seed in (1, BIG_SEED):
         specs = take(seed)
         p = [len(s.prompt) for s in specs]
@@ -55,6 +63,38 @@ def test_lengths_are_clipped_and_the_same_multiset_for_every_seed(mix):
         assert max(a + b - 1 for a, b in zip(p, o)) <= t["max_seq_len"]
     assert sorted(len(s.prompt) for s in take(1)) == \
         sorted(len(s.prompt) for s in take(BIG_SEED))
+
+
+def test_open_arrivals_offer_the_same_gaps_to_every_seed_at_the_rate():
+    """Seed-determined due times; each block of the window the same
+    multiset of gaps whatever the seed, half of it sampled; a mean rate
+    within 1% of ``rate``; and ``warm_s`` seconds of arrivals before 0."""
+    import itertools
+
+    from generators import open as gen_open
+
+    t = _traffic("chat_bursty")
+    block, rate = t["block"], t["rate"]
+    take = lambda seed: list(itertools.islice(gen_open.generate(t, seed, 1000, 60.0), 4 * block))
+    a, b, c = take(BIG_SEED), take(BIG_SEED), take(7)
+    assert [x[0] for x in a] == [x[0] for x in b]
+    assert [x[0] for x in a] != [x[0] for x in c]
+    for run in (a, c):
+        due = np.array([x[0] for x in run])
+        assert (np.diff(due) >= 0).all()
+        warm = due[due < 0]
+        assert warm[0] == -t["warm_s"] and warm[-1] > -t["warm_s"] * 0.2
+        window = [x for x in run if x[0] >= 0]
+        gaps = np.diff([x[0] for x in window[: 3 * block + 1]])
+        for k in range(3):
+            g = gaps[k * block:(k + 1) * block]
+            assert np.allclose(np.sort(g), gen_open.gaps(t, block))
+            assert abs(block / g.sum() - rate) < 0.01 * rate
+            smp = [s for _, s in window[k * block:(k + 1) * block] if s.temperature > 0]
+            assert len(smp) == round(block * t["sampled"]["share"])
+            assert all(s.top_k == t["sampled"]["top_k"] for s in smp)
+    g = gen_open.gaps(t, block)
+    assert g.std() / g.mean() == pytest.approx(t["cv"], rel=0.05)
 
 
 # ---------------------------------------------------------------------------
@@ -79,6 +119,38 @@ def test_qwen2_token_flops_by_hand():
     attn = 2 * 2 * 12 * 128 * ctx
     assert ref.token_flops(s, ctx) == 28 * (per_layer + attn)
     assert 28 * per_layer == 2 * 1_310_195_712   # 1.31e9 matrix parameters
+
+
+def test_mamba2_token_flops_and_bytes_by_hand():
+    ref, cfg, s = _sizes("mamba2-130m")
+    # d 768, d_inner 1536, 24 heads of 64, state 128, one group, conv 4
+    # over 1536 + 2 * 128 = 1792 channels
+    assert (s["di"], s["H"], s["P"], s["N"], s["conv"]) == (1536, 24, 64, 128, 1792)
+    per_layer = 2 * 768 * (2 * 1536 + 2 * 128 + 24) + 2 * 1536 * 768 \
+        + 2 * 4 * 1792 + 5 * 24 * 64 * 128
+    assert ref.token_flops(s, 1) == ref.token_flops(s, 999) == 24 * per_layer
+    state = 24 * 64 * 128 * 4          # 786,432 bytes of float32 state a layer
+    conv = 3 * 1792 * 2                # the last three inputs, bfloat16
+    assert ref.token_bytes(s, 7) == 24 * 2 * (state + conv)
+    assert 24 * state == 18_874_368    # 18.9 MB a slot
+
+
+def test_qwen2_token_bytes_and_backbone_bytes_by_hand():
+    from work import backbone_bytes
+
+    ref, _, s = _sizes("qwen2-1.5b")
+    # 28 layers x (K and V) x 2 heads x 128 x 2 bytes, ctx rows read + one written
+    assert ref.token_bytes(s, 300) == 28 * 2 * 2 * 128 * 2 * 301
+    # the matrices in bfloat16 (1.31e9, as above) and the biases, norm
+    # scales in float32; the embedding left out
+    mats = 1_310_195_712 * 2
+    bias = 28 * (1536 + 2 * 256) * 2
+    norms = (2 * 28 * 1536 + 1536) * 4
+    assert backbone_bytes(ref, s) == mats + bias + norms
+    m_ref, _, m = _sizes("mamba2-130m")
+    per_layer = 768 * (2 * 1536 + 2 * 128 + 24) * 2 + 1536 * 768 * 2 \
+        + 4 * 1792 * 2 + 1792 * 2 + (3 * 24 + 1536 + 768) * 4
+    assert backbone_bytes(m_ref, m) == 24 * per_layer + 768 * 4
 
 
 def test_head_work_by_hand():
@@ -205,6 +277,131 @@ def test_end_to_end_arithmetic_on_a_hand_record():
     assert m["tpot_p95_ms"]["value"] == pytest.approx(100.0)
 
 
+def test_ttft_and_gen_lag_on_a_hand_record():
+    """TTFT from the due time, the close standing in for a first token
+    that never came; a request due before the window is not counted; the
+    generator's lag is sent - due."""
+    from types import SimpleNamespace as NS
+
+    from harness import Record, Tracked, end_to_end, quantile, ttft_s
+    from metrics import gen_lag_p95_ms, ttft_p95_ms
+    from metrics.common import Readings
+
+    done = NS(status=NS(name="COMPLETED"), done=True)
+    live = NS(status=NS(name="ACTIVE"), done=False)
+    spec = NS(prompt=np.zeros(4), temperature=0.0)
+    rec = Record(t_open=10.0, t_close=12.0)
+    rec.tracked = [
+        Tracked(spec, done, 9.6, [9.7, 9.8], due=9.5),           # before the window
+        Tracked(spec, done, 10.0, [10.2, 10.3], due=10.0),       # 200 ms
+        Tracked(spec, done, 10.6, [11.0, 11.1], due=10.5),       # 500 ms, sent 100 late
+        Tracked(spec, live, 11.0, [12.5], due=11.0),             # none by the close: 1000
+        Tracked(spec, live, 11.9, [], due=11.9),                  # none at all: 100
+    ]
+    m = end_to_end(rec, ["ttft_p50_ms"])
+    ttft = [0.2, 0.5, 1.0, 0.1]
+    assert ttft_s(rec) == pytest.approx(ttft)
+    assert m["ttft_p50_ms"]["value"] == pytest.approx(350.0)
+    r = Readings(rec, None, {}, None, 4, 4, 128, {})
+    assert ttft_p95_ms.read(r) == pytest.approx(1e3 * quantile(ttft, 0.95))
+    lag = [0.0, 0.1, 0.0, 0.0]
+    assert gen_lag_p95_ms.read(r) == pytest.approx(1e3 * quantile(lag, 0.95))
+    rec.tracked = [Tracked(spec, done, 10.0, [10.2])]           # a backlog: no due times
+    assert "ttft_p50_ms" not in end_to_end(rec, ["ttft_p50_ms"])
+    assert gen_lag_p95_ms.read(r) is None and ttft_p95_ms.read(r) is None
+
+
+class _FakeClock:
+    def __init__(self):
+        self.t = 100.0
+        self.sleeps = []
+
+    def now(self):
+        return self.t
+
+    def sleep(self, s):
+        self.sleeps.append((self.t, s))
+        self.t += s
+
+
+class _StubSession:
+    """Admits every queued request at a step's start; each step takes
+    ``STEP`` seconds and gives every resident one token."""
+
+    STEP = 0.010
+
+    def __init__(self, clock, on_token):
+        import collections
+        from types import SimpleNamespace as NS
+
+        self.clock, self.on_token, self.slots = clock, on_token, []
+        self.scheduler = NS(queue=collections.deque(),
+                            has_work=lambda: bool(self.scheduler.queue or self.slots))
+
+    def submit(self, req):
+        self.scheduler.queue.append(req)
+
+    def step(self):
+        from repro.train import RequestStatus
+
+        assert self.scheduler.has_work()
+        self.clock.t += self.STEP
+        self.slots += self.scheduler.queue
+        self.scheduler.queue.clear()
+        for req in list(self.slots):
+            req.out_tokens.append(1)
+            self.on_token(req, 1)
+            if len(req.out_tokens) >= req.sampling_params.max_new_tokens:
+                req.status = RequestStatus.COMPLETED
+                self.slots.remove(req)
+        return self.scheduler.has_work()
+
+
+def test_run_open_submits_at_due_times_and_sleeps_when_idle():
+    """On a fake clock: a request due while the loop is idle goes out at
+    its due time, one due during a step at the step's end; the loop
+    sleeps only with nothing to serve, and never past the next due time;
+    the warm stretch's arrivals precede the window, whose own are due from
+    its open."""
+    from types import SimpleNamespace as NS
+
+    import harness
+    from generators.common import Spec
+
+    clock = _FakeClock()
+    cell = NS(traffic={"warm_s": 1.0})
+    drv = harness.Runner(cell, 1, 1.0)
+    drv.clock, drv.sleep = clock.now, clock.sleep
+    stub = _StubSession(clock, drv.on_token)
+    drv.server = NS(session=stub)
+    spec = lambda n: Spec(np.zeros(4, np.int32), n)
+    arrivals = [(-0.5, spec(2)), (-0.05, spec(2)), (0.0, spec(3)), (0.003, spec(1)),
+                (0.5, spec(1)), (0.9999, spec(5)), (2.0, spec(1))]
+    rec = harness.Record()
+    sleeps_with_work = []
+    real_sleep = clock.sleep
+
+    def sleep(s):
+        sleeps_with_work.append(stub.scheduler.has_work())
+        real_sleep(s)
+
+    drv.sleep = sleep
+    drv._run_open(iter(arrivals), rec, NS(total=0), None, 90.0, 1.0)
+    assert not any(sleeps_with_work)
+    assert rec.setup_s == pytest.approx(11.0) and rec.t_open == pytest.approx(101.0)
+    assert rec.t_close == pytest.approx(102.0, abs=stub.STEP)
+    due = [t.due for t in rec.tracked]
+    sent = [t.sent for t in rec.tracked]
+    assert due == pytest.approx([100.5, 100.95, 101.0, 101.003, 101.5, 101.9999])
+    # idle before each but the one due mid-step (sent as that step ends)
+    assert sent == pytest.approx([100.5, 100.95, 101.0, 101.01, 101.5, 101.9999])
+    assert all(t.times[0] == pytest.approx(t.sent + stub.STEP) for t in rec.tracked)
+    # the loop slept until each due time, and up to the window's open
+    wake = [round(t + s, 6) for t, s in clock.sleeps]
+    assert wake == pytest.approx([100.5, 100.95, 101.0, 101.5, 101.9999])
+    assert rec.queue_open == 0 and rec.queue_close == 0
+
+
 PER_LAYER = ("itl_p50_ms", "itl_p95_ms", "prefill_chunk_ms", "batch_occupancy",
              "decode_step_ms", "head_ms_per_step", "ds_head_roofline", "idle_share",
              "decode_mfu")
@@ -246,10 +443,47 @@ def test_per_layer_readers_on_a_hand_run():
         "decode_mfu": 100 * flops / 197e12})
     empty = Readings(Record(), None, s, ref, 64, 16, 19072, peak)
     assert all(importlib.import_module(f"metrics.{n}").read(empty) is None
-               for n in PER_LAYER)
+               for n in PER_LAYER + ("decode_roofline", "gen_lag_p95_ms", "ttft_p95_ms"))
 
 
-def test_rehearsal_of_a_run_on_the_cpu():
+@pytest.mark.parametrize("config", ["qwen2-1.5b", "mamba2-130m"])
+def test_decode_roofline_on_a_hand_run(config):
+    """The whole decode step's least time over its device time: per step,
+    the active slots' tokens (a prefill's first token is not one), their
+    cache or state bytes, the weights once and the head's least work."""
+    from types import SimpleNamespace as NS
+
+    from harness import Record, Tracked
+    from metrics import decode_roofline
+    from metrics.common import Readings
+    from trace_reduce import Reduction
+    from work import backbone_bytes, head_bytes, head_flops, peaks
+
+    ref, _, s = _sizes(config)
+    d, K, v_pad = s["d"], 8, 12672
+    done = NS(status=NS(name="COMPLETED"), done=True)
+    rec = Record(t_open=0.0, t_close=1.0)
+    rec.steps = [(0.0, 0.5, 3), (0.5, 1.0, 2)]
+    rec.tracked = [Tracked(NS(prompt=np.zeros(4)), done, 0.0, [0.1, 0.4, 0.9]),
+                   Tracked(NS(prompt=np.zeros(9)), done, 0.0, [0.6, 0.7])]
+    red = Reduction(window_s=1.0, busy_s=0.8, n_devices=1,
+                    modules={"jit__decode": [0.02, 0.03]})
+    r = Readings(rec, red, s, ref, 128, K, v_pad, peaks("TPU v5 lite"))
+
+    def least(ctxs, hit):
+        B = len(ctxs)
+        f = sum(ref.token_flops(s, c) for c in ctxs) + head_flops(B, K, v_pad, d)
+        b = backbone_bytes(ref, s) + B * d * 2 + sum(ref.token_bytes(s, c) for c in ctxs) \
+            + head_bytes(B, K, v_pad, d, hit)
+        return max(f / 197e12, b / 819e9)
+
+    want = 100 * np.mean([least([5], 3), least([6, 10], 2)]) / 25e-3
+    assert decode_roofline.read(r) == pytest.approx(want)
+    assert 0 < want < 100
+
+
+@pytest.mark.parametrize("name", ["qwen2-1.5b.decode_backlog", "mamba2-130m.chat_bursty"])
+def test_rehearsal_of_a_run_on_the_cpu(name):
     """The whole run of a tiny cell, the command's chip check aside: the
     result line's keys, the metrics of the cell, and ``correct``."""
     import harness
@@ -257,9 +491,9 @@ def test_rehearsal_of_a_run_on_the_cpu():
     import tiny
     from work import peaks
 
-    cell = tiny.qwen2_cell()
+    cell = tiny.qwen2_cell() if name.startswith("qwen2") else tiny.mamba2_cell()
     bench = harness.load_json(harness.ROOT, "BENCHMARK.json")
-    name = cell.name = "qwen2-1.5b.decode_backlog"
+    cell.name = name
     res = run.run_cell(cell, BIG_SEED, 3.0, False, bench, peaks("TPU v5 lite"),
                        harness.device_info())
     assert list(res)[:3] == ["correct", "attempted", "failed"]
@@ -269,3 +503,22 @@ def test_rehearsal_of_a_run_on_the_cpu():
             if name in m.get("workloads", [name])}
     assert set(res["metrics"]) == want
     assert res["attempted"] > 0 and res["failed"] == 0
+
+
+def test_every_per_layer_metric_has_a_reader_and_moves_what_its_cells_report():
+    """``BENCHMARK.json``: each per-layer metric has its reader under
+    ``metrics/`` (``<name>.<part>``, a quantity split by what it moves,
+    that of ``<name>``), and every cell it lists reports the end-to-end
+    metric it moves; every cell reports ``setup_s``, another end-to-end
+    metric and a per-layer one."""
+    bench = json.load(open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")))
+    cells = [w["name"] for w in bench["workloads"]]
+    reports = {c: {m["name"] for m in bench["end_to_end"]
+                   if c in m.get("workloads", cells)} for c in cells}
+    for m in bench["per_layer"]:
+        reader = m["name"].split(".")[0]
+        assert os.path.exists(os.path.join(BENCH, "metrics", reader + ".py")), m
+        assert all(m["moves"] in reports[c] for c in m.get("workloads", cells)), m
+    for c in cells:
+        assert "setup_s" in reports[c] and len(reports[c]) >= 2
+        assert any(c in m.get("workloads", cells) for m in bench["per_layer"])

@@ -30,6 +30,24 @@ def qwen2_cell(traffic: str = "decode_backlog"):
     return Cell("tiny.qwen2", c, t)
 
 
+def mamba2_cell(traffic: str = "chat_bursty"):
+    from harness import Cell
+
+    c = _load("configs", "mamba2-130m.json")
+    c.update(d_model=64, n_layer=2, vocab_size=512)
+    c["defaults"] = dict(c["defaults"], d_state=16, headdim=16, chunk_size=8)
+    c["program"] = {"arch": "mamba2-130m", "overrides": dict(
+        n_layers=2, d_model=64, vocab_size=512, ssm_state=16, ssm_headdim=16,
+        ssm_chunk=8)}
+    c["ds_head"] = dict(c["ds_head"], num_experts=4)
+    c["serve"] = dict(c["serve"], prefill_chunk=16)
+    c["correct"] = {"logit_gap_limit": 0.01}
+    t = _tiny_traffic(_load("traffic", traffic + ".json"))
+    if t["kind"] == "open":
+        t.update(rate=40.0, warm_s=0.5, block=16)
+    return Cell("tiny.mamba2", c, t)
+
+
 def _tiny_traffic(t: dict) -> dict:
     t = copy.deepcopy(t)
     t.update(slots=4, max_seq_len=64, block=8)

@@ -5,7 +5,8 @@ runs, a decode token of a dense model costs its matrix products, its
 attention over the positions it really has, and the DS head's gate plus
 one expert's ``v_pad`` rows. Each architecture's per-token backbone count
 lives beside its reference (``reference/<name>.py`` ``token_flops``) and
-is built from the pieces here.
+is built from the pieces here, as do the bytes of one slot's cache or
+state that a decode token moves (``token_bytes``).
 """
 from __future__ import annotations
 
@@ -55,6 +56,17 @@ def head_bytes(B: int, K: int, v_pad: int, d: int, n_hit: int,
     """Least bytes the head must move: the rows of each expert that got
     at least one token, the gate, and the hidden states."""
     return n_hit * v_pad * d * wbytes + K * d * wbytes + B * d * hbytes
+
+
+def backbone_bytes(reference, s: dict) -> int:
+    """Bytes of the weights that one decode step reads once whatever its
+    batch: every array of ``reference.init`` but the embedding table, of
+    which a step reads one row a token (counted apart)."""
+    import jax
+
+    shapes = jax.eval_shape(lambda k: reference.init(k, s), jax.random.PRNGKey(0))
+    return sum(x.size * x.dtype.itemsize for name, tree in shapes.items()
+               if name != "embed" for x in jax.tree.leaves(tree))
 
 
 def least_time(flops: float, nbytes: float, peak: dict) -> tuple[float, str]:
